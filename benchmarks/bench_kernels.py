@@ -18,10 +18,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.launch.roofline import HW, analyze_hlo
+from repro.launch.roofline import analyze_hlo
 from repro.kernels import fused_vma_dots, fused_vma_dots_ref
 from repro.kernels.common import launches_per_iteration
 from repro.obs import structural_bytes_per_elem
+from repro.obs.report import hbm_peak
 
 from .common import bench_record, emit, seed_key, timeit_call, write_bench_json
 
@@ -41,6 +42,7 @@ def iteration_cores(grid: int = 24, maxiter: int = 20, json_path: str | None = N
     A = poisson27(grid)
     b = jnp.sin(jnp.arange(A.n, dtype=jnp.float32))
     backend = jax.default_backend()
+    peak = hbm_peak(jax.devices()[0].device_kind)  # None off the known chips
     record = bench_record(
         "kernels",
         n=int(A.n),
@@ -48,7 +50,7 @@ def iteration_cores(grid: int = 24, maxiter: int = 20, json_path: str | None = N
         maxiter=int(maxiter),
         backend=backend,
         interpret_kernels=backend != "tpu",
-        hbm_peak_gbs=HW["hbm_bw"] / 1e9,
+        hbm_peak_gbs=None if peak is None else peak / 1e9,
         cores={},
     )
     for core in ("jnp", "pallas", "fused_iter"):
@@ -68,7 +70,7 @@ def iteration_cores(grid: int = 24, maxiter: int = 20, json_path: str | None = N
             "launches_per_iter": launches,
             "bytes_per_elem": bpe,
             "achieved_gbs": gbs,
-            "frac_of_hbm_peak": gbs / (HW["hbm_bw"] / 1e9),
+            "frac_of_hbm_peak": None if peak is None else gbs / (peak / 1e9),
             "trace_count": p.trace_count,
         }
         emit(

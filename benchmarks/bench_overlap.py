@@ -20,104 +20,95 @@ STRUCTURAL by tools/bench_gate.py (any increase fails CI) and whose
 within-10%-of-pipecg acceptance criterion, enforced against the
 committed trajectory. ``time_per_iter_us`` rides the timing band.
 
-Runs in a subprocess with 8 virtual devices (the only place a
-multi-device mesh exists on this CPU box).
+Runs in the calling process on every device it finds (at least 2): the
+chips of a TPU host, or on CPU the virtual devices that
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` in the environment
+gives. Fewer than 2 devices is an error, which makes ``benchmarks.run``
+exit non-zero.
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-_CHILD = r"""
-import os, time, json
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp, numpy as np
-from repro.core import jacobi
-from repro.core.distributed import (make_solver_mesh, build_distributed_solver,
-                                    get_method)
-from repro.kernels.common import while_body_jaxpr, count_primitive
-from repro.sparse import balanced_rows, poisson27, shard_dia, shard_vector, spmv
-
-A = poisson27(12)
-xstar = jnp.ones((A.n,)) / jnp.sqrt(A.n)
-b = spmv(A, xstar)
-M = jacobi(A)
-bounds = balanced_rows(A.n, 8)
-As = shard_dia(A, bounds)
-mesh1 = make_solver_mesh(8)
-mesh2 = make_solver_mesh(8, sub=4)
-bsh = shard_vector(b, bounds)
-ish = shard_vector(M.inv_diag, bounds)
+import time
 
 TIMED_ITERS = 64
-out = {"devices": 8, "n": A.n, "methods": {}}
-for method in ("h1", "h2", "h3", "h4", "pl2", "pl3"):
-    mesh = mesh2 if method == "h4" else mesh1
-    depth = get_method(method).pipeline_depth
-    runner = build_distributed_solver(As, mesh=mesh, method=method,
-                                      maxiter=TIMED_ITERS, replace_every=50)
-    run = jax.jit(lambda bb, ii, a, r: runner(bb, ii, a, r))
-
-    # structural census on the RR-free program: the steady-state schedule.
-    # (residual replacement adds a lax.cond branch whose collectives would
-    # be counted statically but execute only every replace_every iters)
-    census_runner = build_distributed_solver(As, mesh=mesh, method=method,
-                                             maxiter=TIMED_ITERS)
-    closed = jax.make_jaxpr(lambda bb, ii, a, r: census_runner(bb, ii, a, r))(
-        bsh, ish, jnp.float32(1e-6), jnp.float32(0.0))
-    body = while_body_jaxpr(closed.jaxpr)
-    red = count_primitive(body, "psum") / depth
-    pp = count_primitive(body, "ppermute") / depth
-    ag = count_primitive(body, "all_gather") / depth
-
-    # convergence: iterations to atol on the Poisson problem
-    res = run(bsh, ish, jnp.float32(1e-6), jnp.float32(0.0))
-    iters = int(jax.block_until_ready(res.iterations))
-
-    # timing: fixed-work solve (atol=0 -> all TIMED_ITERS iterations)
-    jax.block_until_ready(run(bsh, ish, jnp.float32(0.0), jnp.float32(0.0)))
-    times = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        jax.block_until_ready(run(bsh, ish, jnp.float32(0.0), jnp.float32(0.0)))
-        times.append(time.perf_counter() - t0)
-    us_per_iter = sorted(times)[1] / TIMED_ITERS * 1e6
-
-    out["methods"][method] = {
-        "pipeline_depth": depth,
-        "reductions_per_iter": red,
-        "ppermutes_per_iter": pp,
-        "allgathers_per_iter": ag,
-        "iterations": iters,
-        "time_per_iter_us": round(us_per_iter, 1),
-    }
-    print(f"overlap/{method},{us_per_iter:.1f},"
-          f"red/it={red:g};ppermute/it={pp:g};allgather/it={ag:g};iters={iters}")
-print("BENCHJSON:" + json.dumps(out))
-"""
 
 
 def main(json_path: str | None = None):
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    out = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
-                         text=True, env=env, timeout=900)
-    if out.returncode != 0:
-        print(f"overlap/FAILED,0,{out.stderr[-300:]!r}")
-        return
-    payload = None
-    for line in out.stdout.splitlines():
-        if line.startswith("BENCHJSON:"):
-            payload = json.loads(line[len("BENCHJSON:"):])
-        else:
-            sys.stdout.write(line + "\n")
-    if json_path and payload is not None:
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import jacobi
+    from repro.core.distributed import build_distributed_solver, get_method, make_solver_mesh
+    from repro.kernels.common import count_primitive, while_body_jaxpr
+    from repro.sparse import balanced_rows, poisson27, shard_dia, shard_vector, spmv
+
+    n_dev = len(jax.devices())
+    if n_dev < 2:
+        raise RuntimeError(
+            f"overlap needs >= 2 devices, found {n_dev} ({jax.devices()[0].platform}); "
+            "on CPU set XLA_FLAGS=--xla_force_host_platform_device_count=8 before launch"
+        )
+    shards = n_dev - n_dev % 2  # the h4 pod mesh needs an even count
+    A = poisson27(12)
+    xstar = jnp.ones((A.n,)) / jnp.sqrt(A.n)
+    b = spmv(A, xstar)
+    M = jacobi(A)
+    bounds = balanced_rows(A.n, shards)
+    As = shard_dia(A, bounds)
+    mesh1 = make_solver_mesh(shards)
+    mesh2 = make_solver_mesh(shards, sub=shards // 2)
+    bsh = shard_vector(b, bounds)
+    ish = shard_vector(M.inv_diag, bounds)
+
+    out = {"devices": shards, "n": A.n, "methods": {}}
+    for method in ("h1", "h2", "h3", "h4", "pl2", "pl3"):
+        mesh = mesh2 if method == "h4" else mesh1
+        depth = get_method(method).pipeline_depth
+        runner = build_distributed_solver(As, mesh=mesh, method=method,
+                                          maxiter=TIMED_ITERS, replace_every=50)
+        run = jax.jit(lambda bb, ii, a, r, runner=runner: runner(bb, ii, a, r))
+
+        # structural census on the RR-free program: the steady-state schedule.
+        # (residual replacement adds a lax.cond branch whose collectives would
+        # be counted statically but execute only every replace_every iters)
+        census_runner = build_distributed_solver(As, mesh=mesh, method=method,
+                                                 maxiter=TIMED_ITERS)
+        closed = jax.make_jaxpr(lambda bb, ii, a, r: census_runner(bb, ii, a, r))(
+            bsh, ish, jnp.float32(1e-6), jnp.float32(0.0))
+        body = while_body_jaxpr(closed.jaxpr)
+        red = count_primitive(body, "psum") / depth
+        pp = count_primitive(body, "ppermute") / depth
+        ag = count_primitive(body, "all_gather") / depth
+
+        # convergence: iterations to atol on the Poisson problem
+        res = run(bsh, ish, jnp.float32(1e-6), jnp.float32(0.0))
+        iters = int(jax.block_until_ready(res.iterations))
+
+        # timing: fixed-work solve (atol=0 -> all TIMED_ITERS iterations)
+        jax.block_until_ready(run(bsh, ish, jnp.float32(0.0), jnp.float32(0.0)))
+        times = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(bsh, ish, jnp.float32(0.0), jnp.float32(0.0)))
+            times.append(time.perf_counter() - t0)
+        us_per_iter = sorted(times)[1] / TIMED_ITERS * 1e6
+
+        out["methods"][method] = {
+            "pipeline_depth": depth,
+            "reductions_per_iter": red,
+            "ppermutes_per_iter": pp,
+            "allgathers_per_iter": ag,
+            "iterations": iters,
+            "time_per_iter_us": round(us_per_iter, 1),
+        }
+        print(f"overlap/{method},{us_per_iter:.1f},"
+              f"red/it={red:g};ppermute/it={pp:g};allgather/it={ag:g};iters={iters}",
+              flush=True)
+    if json_path:
         from .common import bench_record, write_bench_json
 
-        write_bench_json(json_path, bench_record("overlap", **payload))
+        write_bench_json(json_path, bench_record("overlap", **out))
+    return out
 
 
 if __name__ == "__main__":

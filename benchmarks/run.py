@@ -6,7 +6,7 @@ Prints ``name,us_per_call,derived`` CSV rows.
   bench_solver_methods  — Fig. 6/7: method comparison across matrices
   bench_kernels         — §V-B: kernel fusion effect (time + HBM traffic)
   bench_overlap         — h1..h4/pl2/pl3 collective schedules + time/iter
-                          (8-dev subprocess; JSON-capable, CI-gated)
+                          (needs >= 2 devices; JSON-capable, CI-gated)
   bench_serve           — async serving tier: queue wait p50/p95, bucket
                           occupancy, programs compiled (JSON, CI-gated)
   bench_poisson         — Fig. 8: 125-pt Poisson + perf-model decomposition
@@ -70,6 +70,9 @@ def main(argv=None) -> None:
     ap.add_argument("--obs-dump", metavar="PATH", default=None,
                     help="enable observability; dump spans+metrics JSON here")
     args = ap.parse_args(argv)
+    from repro.launch.env import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.obs_dump:
         from repro.obs import clear_spans, enable, reset_metrics

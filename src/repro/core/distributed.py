@@ -58,7 +58,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..obs.trace import trace_scope
 from ..sparse.partition import ShardedDIA
 from .iteration import get_core, make_deep_pipecg_core, run_pipecg
@@ -453,9 +452,13 @@ def build_distributed_solver(
             kwargs["core"] = core
         return loop(b, jnp.zeros_like(b), **kwargs)
 
+    # check_vma=False: the Pallas iteration cores run inside this block,
+    # and their kernel bodies mix replicated scalars (alpha, beta) with
+    # row-sharded vector blocks, which the varying-axes check refuses
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
+        check_vma=False,
         in_specs=(spec_mat, spec_scalar, spec_rhs, spec_vec, P(), P()),
         out_specs=(spec_rhs, P(), P(), P(), P()),
     )

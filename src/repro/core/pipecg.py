@@ -11,7 +11,7 @@ reduction) and the method x reducer selection matrix are documented in
 docs/distributed.md.
 
 What this file *does* own is the **padded execution path**: the Pallas
-cores want LANE-aligned tiles, and padding ten vectors every iteration
+cores want whole row tiles, and padding ten vectors every iteration
 would dominate the fused kernel's saving. For DIA operators with an
 elementwise (Jacobi/identity) preconditioner, the solve runs entirely on
 views zero-padded ONCE — operator diagonals, b, x0, inv_diag — sized so
@@ -24,12 +24,13 @@ diagonal data on the plan.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from ..kernels.common import LANE, ceil_to, pad1d
+from ..kernels.common import LANE, ceil_to, pad1d, row_tile
 from ..obs.trace import trace_scope
 from ..sparse.formats import DIAMatrix
 from ..sparse.spmv import resolve_engine, spmv, spmv_dia, spmv_dia_bf16
@@ -50,17 +51,23 @@ def _elementwise_pc(M) -> bool:
 
 
 def _padded_tile(core_name: str, bandwidth: int, tile: int | None) -> int:
-    """One tile size satisfying every kernel constraint of this core."""
+    """The banded-SPMV row tile of this core (>= bandwidth + 1, whole
+    1-D layout tiles — ``kernels.common.row_tile``)."""
     if core_name == "fused_iter":
         from ..kernels.fused_iter import fused_iter_tile
 
         return fused_iter_tile(bandwidth, tile)
-    # pallas VMA core + banded SPMV: align to both the fused_vma 2-D tile
-    # (TILE_ROWS * LANE) and the SPMV halo (>= bandwidth, LANE-aligned)
     from ..kernels.fused_vma.kernel import TILE_ROWS
 
-    t = max(tile or TILE_ROWS * LANE, ceil_to(bandwidth + 1, LANE))
-    return ceil_to(t, TILE_ROWS * LANE)
+    return row_tile(bandwidth, tile or TILE_ROWS * LANE)
+
+
+def _padded_length(n: int, t: int) -> int:
+    """Padded vector length of the pallas core: whole SPMV row tiles AND
+    whole fused_vma (TILE_ROWS, LANE) blocks, so neither kernel pads."""
+    from ..kernels.fused_vma.kernel import TILE_ROWS
+
+    return ceil_to(n, math.lcm(t, TILE_ROWS * LANE))
 
 
 def _padded_spmv_fns(Ap: DIAMatrix, spmv_engine: str, t: int):
@@ -130,7 +137,7 @@ def _pipecg_impl(
     else:
         core = get_core(core_name)
         t = _padded_tile(core_name, A.bandwidth, tile)
-        n_pad = ceil_to(n, t)
+        n_pad = _padded_length(n, t)
     with trace_scope("pipecg.pad"):  # once per solve, never in the loop
         Ap = DIAMatrix(jnp.pad(A.data, ((0, 0), (0, n_pad - n))), A.offsets, n_pad)
         bp = pad1d(b, n_pad)

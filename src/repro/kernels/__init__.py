@@ -7,7 +7,8 @@ fused_vma  — PIPECG iteration core: 8 VMAs + Jacobi PC + dot partials,
              one HBM pass (paper §V-B kernel fusion, extended).
 fused_dot  — gamma/delta/(u,u) in one pass (merged reductions).
 spmv_dia   — banded/stencil SPMV (TPU-native replacement for CSR SPMV).
-spmv_bell  — Block-ELLPACK SPMV for general sparsity.
+spmv_bell  — Block-ELLPACK SPMV for general sparsity (interpret mode only:
+             its gather does not compile for TPU, so no solver path uses it).
 fused_adam — the fusion idea applied to the LM training substrate.
 flash_attn — single-pass causal attention (online softmax in VMEM scratch);
              the fix for the memory-dominant roofline cells (§Perf).

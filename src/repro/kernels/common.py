@@ -1,9 +1,8 @@
 """Shared Pallas kernel utilities.
 
 All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated on CPU with ``interpret=True``, which executes the kernel body in
-Python. ``interpret_default()`` picks the right mode for the current
-backend; tests may force it via ``FORCE_INTERPRET``.
+validated on CPU with ``interpret=True``. ``interpret_default()`` picks
+the mode from the backend: compiled on TPU, interpreted elsewhere.
 """
 from __future__ import annotations
 
@@ -12,19 +11,67 @@ import jax.numpy as jnp
 
 LANE = 128        # TPU vector lane width
 SUBLANE = 8       # float32 sublane count; (8, 128) is the native f32 tile
-
-# Test hook: None -> auto (interpret on CPU, compiled on TPU).
-FORCE_INTERPRET: bool | None = None
+ROW_ALIGN = 1024  # TPU lays 1-D f32 arrays out in tiles of 1024 elements
 
 
 def interpret_default() -> bool:
-    if FORCE_INTERPRET is not None:
-        return FORCE_INTERPRET
+    """Interpret mode off-TPU (CPU validation); compiled kernels on TPU."""
     return jax.default_backend() != "tpu"
 
 
 def ceil_to(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def row_tile(bandwidth: int, tile: int) -> int:
+    """The 1-D row tile of the banded kernels: at least ``tile`` and
+    ``bandwidth + 1`` (the halo lives in one neighbor tile), rounded up
+    to whole 1-D layout tiles (``ROW_ALIGN``) so the chip accepts the
+    block and the in-kernel concatenation of neighbor tiles."""
+    return ceil_to(max(tile, bandwidth + 1), ROW_ALIGN)
+
+
+# Per-tile dot partials leave a kernel as one (SUBLANE, LANE) block per grid
+# step — the smallest f32 block the chip accepts — with the partials in
+# lanes 0..2 of row 0 and zeros elsewhere.
+
+def dot_partials_block(part: jax.Array) -> jax.Array:
+    """(3,) partials -> the (SUBLANE, LANE) block a kernel writes."""
+    return jnp.pad(part[None, :], ((0, SUBLANE - 1), (0, LANE - part.shape[0])))
+
+
+def dot_partials_out(tiles: int):
+    """(out_spec, out_shape) of the per-tile dot-partials output."""
+    from jax.experimental import pallas as pl
+
+    return (pl.BlockSpec((SUBLANE, LANE), lambda i: (i, 0)),
+            jax.ShapeDtypeStruct((SUBLANE * tiles, LANE), jnp.float32))
+
+
+def sum_dot_partials(parts: jax.Array) -> jax.Array:
+    """(SUBLANE * tiles, LANE) partials -> float32 [(r,u), (w,u), (u,u)]."""
+    return parts[::SUBLANE, :3].sum(axis=0)
+
+
+def lane_batched(fn):
+    """``fn`` (a kernel wrapper over arrays) made vmappable on the chip.
+
+    Left to itself, ``jax.vmap`` of a ``pallas_call`` adds a squeezed
+    batch dimension to every block, and the TPU compiler refuses that for
+    1-D vector blocks and scalar operands. Under ``vmap`` this runs
+    ``fn`` once per batch lane instead — the same kernel as the unbatched
+    call, one launch per right-hand side.
+    """
+    wrapped = jax.custom_batching.custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def _per_lane(axis_size, in_batched, *args):
+        lanes = [fn(*(a[i] if bat else a for a, bat in zip(args, in_batched)))
+                 for i in range(axis_size)]
+        out = jax.tree.map(lambda *xs: jnp.stack(xs), *lanes)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return wrapped
 
 
 def pad1d(x: jax.Array, n_pad: int) -> jax.Array:
@@ -45,14 +92,14 @@ def as_2d(x: jax.Array, lane: int = LANE) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _sub_jaxprs(params):
-    from jax import core as jcore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jcore.ClosedJaxpr):
+            if isinstance(item, ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jcore.Jaxpr):
+            elif isinstance(item, Jaxpr):
                 yield item
 
 

@@ -2,16 +2,15 @@
 
 Unfused, the three dots read 6N elements (u three times); fused they read
 3N — the same merged-reads idea the paper applies to the CPU side (§V-B.2).
-Per-tile partials are emitted to a (tiles, LANE) buffer; the wrapper sums
-them (exact f32 tree-sum of tile partials).
+Per-tile partials are emitted as one (8, LANE) block per tile; the wrapper
+sums them (exact f32 tree-sum of tile partials).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import LANE
+from ..common import LANE, dot_partials_block, dot_partials_out
 
 TILE_ROWS = 64
 
@@ -21,7 +20,7 @@ def _kernel(r_ref, u_ref, w_ref, dots_o):
     uf = u_ref[...].astype(jnp.float32)
     wf = w_ref[...].astype(jnp.float32)
     partial = jnp.stack([jnp.sum(rf * uf), jnp.sum(wf * uf), jnp.sum(uf * uf)])
-    dots_o[...] = jnp.pad(partial[None, :], ((0, 0), (0, LANE - 3)))
+    dots_o[...] = dot_partials_block(partial)
 
 
 def fused_dots_padded(r, u, w, *, interpret: bool):
@@ -29,12 +28,13 @@ def fused_dots_padded(r, u, w, *, interpret: bool):
     assert rows % TILE_ROWS == 0
     tiles = rows // TILE_ROWS
     vec_spec = pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0))
+    dots_spec, dots_shape = dot_partials_out(tiles)
     fn = pl.pallas_call(
         _kernel,
         grid=(tiles,),
         in_specs=[vec_spec] * 3,
-        out_specs=pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((tiles, LANE), jnp.float32),
+        out_specs=dots_spec,
+        out_shape=dots_shape,
         interpret=interpret,
     )
     return fn(r, u, w)

@@ -5,7 +5,7 @@ from functools import partial
 
 import jax
 
-from ..common import LANE, as_2d, ceil_to, interpret_default, pad1d
+from ..common import LANE, as_2d, ceil_to, interpret_default, pad1d, sum_dot_partials
 from .kernel import TILE_ROWS, fused_dots_padded
 
 __all__ = ["fused_dots"]
@@ -17,7 +17,7 @@ def _fused(r, u, w, interpret: bool):
     n_pad = ceil_to(n, TILE_ROWS * LANE)
     r2, u2, w2 = (as_2d(pad1d(v, n_pad)) for v in (r, u, w))
     parts = fused_dots_padded(r2, u2, w2, interpret=interpret)
-    return parts[:, :3].sum(axis=0)
+    return sum_dot_partials(parts)
 
 
 def fused_dots(r, u, w, interpret: bool | None = None):

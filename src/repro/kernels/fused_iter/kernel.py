@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import LANE
+from ..common import dot_partials_block, dot_partials_out
 
 TILE = 4096  # 1-D row tile; must be >= matrix bandwidth (halo = 1 tile)
 
@@ -55,7 +55,7 @@ def _kernel(
     mwin = jnp.concatenate([ml_ref[...], mc_ref[...], mr_ref[...]])
     acc = jnp.zeros((tile,), jnp.float32)
     for j, o in enumerate(offsets):
-        seg = jax.lax.dynamic_slice(mwin, (tile + o,), (tile,))
+        seg = mwin[tile + o: 2 * tile + o]
         acc = acc + dat_ref[j, :].astype(jnp.float32) * seg.astype(jnp.float32)
     n_v = acc.astype(dtype)
 
@@ -89,7 +89,7 @@ def _kernel(
     uf = u_n.astype(jnp.float32)
     wf = w_n.astype(jnp.float32)
     part = jnp.stack([jnp.sum(rf * uf), jnp.sum(wf * uf), jnp.sum(uf * uf)])
-    dots_o[...] = jnp.pad(part[None, :], ((0, 0), (0, LANE - 3)))
+    dots_o[...] = dot_partials_block(part)
 
 
 def fused_iter_padded(data, offsets, vecs, inv_diag, alpha, beta, *, tile: int, interpret: bool):
@@ -98,7 +98,7 @@ def fused_iter_padded(data, offsets, vecs, inv_diag, alpha, beta, *, tile: int, 
     data (k, n_pad) zero-padded DIA diagonals; vecs = (z, q, s, p, x, r,
     u, w, m) each (n_pad,) with n_pad % tile == 0; bandwidth <= tile.
     Returns 9 updated vectors (z q s p x r u w m) + per-tile dot partials
-    (tiles, LANE).
+    (SUBLANE * tiles, LANE) — see ``common.sum_dot_partials``.
     """
     n_pad = vecs[0].shape[0]
     assert n_pad % tile == 0, (n_pad, tile)
@@ -109,9 +109,9 @@ def fused_iter_padded(data, offsets, vecs, inv_diag, alpha, beta, *, tile: int, 
     z, q, s, p, x, r, u, w, m = vecs
     vec_spec = pl.BlockSpec((tile,), lambda i: (i,))
     scalar_spec = pl.BlockSpec((1,), lambda i: (0,))
-    out_shapes = [jax.ShapeDtypeStruct((n_pad,), dtype) for _ in range(9)]
-    out_shapes.append(jax.ShapeDtypeStruct((tiles, LANE), jnp.float32))
-    out_specs = [vec_spec] * 9 + [pl.BlockSpec((1, LANE), lambda i: (i, 0))]
+    dots_spec, dots_shape = dot_partials_out(tiles)
+    out_shapes = [jax.ShapeDtypeStruct((n_pad,), dtype) for _ in range(9)] + [dots_shape]
+    out_specs = [vec_spec] * 9 + [dots_spec]
 
     fn = pl.pallas_call(
         partial(_kernel, offsets, tile),

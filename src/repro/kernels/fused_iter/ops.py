@@ -12,7 +12,7 @@ from functools import partial
 
 import jax
 
-from ..common import LANE, ceil_to, interpret_default
+from ..common import ROW_ALIGN, interpret_default, lane_batched, row_tile, sum_dot_partials
 from .kernel import TILE, fused_iter_padded
 
 __all__ = ["fused_iter_step", "fused_iter_tile", "trace_count"]
@@ -26,9 +26,9 @@ def trace_count() -> int:
 
 
 def fused_iter_tile(bandwidth: int, tile: int | None = None) -> int:
-    """The row-tile the kernel will use: LANE-aligned, >= bandwidth + 1."""
-    t = tile or TILE
-    return max(t, ceil_to(bandwidth + 1, LANE))
+    """The row-tile the kernel will use: >= bandwidth + 1, whole 1-D
+    layout tiles (``common.row_tile``)."""
+    return row_tile(bandwidth, tile or TILE)
 
 
 @partial(jax.jit, static_argnames=("offsets", "tile", "interpret"))
@@ -40,7 +40,7 @@ def _step(data, z, q, s, p, x, r, u, w, m, inv_diag, alpha, beta,
         data, offsets, (z, q, s, p, x, r, u, w, m), inv_diag, alpha, beta,
         tile=tile, interpret=interpret,
     )
-    dots = outs[9][:, :3].sum(axis=0)
+    dots = sum_dot_partials(outs[9])
     return tuple(outs[:9]) + (dots,)
 
 
@@ -49,14 +49,14 @@ def fused_iter_step(data, offsets, z, q, s, p, x, r, u, w, m, inv_diag,
     """One fused PIPECG iteration: SPMV + 8 VMAs + Jacobi PC + dot partials.
 
     All vector operands and ``data``'s row length must be pre-padded to a
-    multiple of ``tile`` (>= bandwidth, LANE-aligned — see
+    multiple of ``tile`` (>= bandwidth, ROW_ALIGN-aligned — see
     :func:`fused_iter_tile`). Returns (z', q', s', p', x', r', u', w', m',
     dots) with dots = float32 [ (r',u'), (w',u'), (u',u') ].
     """
     if interpret is None:
         interpret = interpret_default()
     n_pad = z.shape[0]
-    if n_pad % tile or tile % LANE:
+    if n_pad % tile or tile % ROW_ALIGN:
         raise ValueError(f"operands must be pre-padded: n_pad={n_pad}, tile={tile}")
-    return _step(data, z, q, s, p, x, r, u, w, m, inv_diag, alpha, beta,
-                 offsets, tile, interpret)
+    run = lane_batched(partial(_step, offsets=offsets, tile=tile, interpret=interpret))
+    return run(data, z, q, s, p, x, r, u, w, m, inv_diag, alpha, beta)

@@ -13,8 +13,8 @@ HBM-bandwidth-bound pass:
 versus 8 separate AXPYs + PC + 3 dots = 27 N reads + 9 N writes unfused.
 
 Layout: vectors are zero-padded to a multiple of (TILE_ROWS*LANE) and viewed
-as (rows, 128); the grid walks row-tiles; per-tile dot partials land in a
-(tiles, 128) buffer summed by the wrapper (padding contributes zeros).
+as (rows, 128); the grid walks row-tiles; per-tile dot partials land in one
+(8, 128) block per tile, summed by the wrapper (padding contributes zeros).
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import LANE
+from ..common import LANE, dot_partials_block, dot_partials_out
 
 TILE_ROWS = 32  # (32, 128) f32 tile = 16 KiB per operand per grid step
 
@@ -65,14 +65,14 @@ def _kernel(
     uf = u_n.astype(jnp.float32)
     wf = w_n.astype(jnp.float32)
     partial = jnp.stack([jnp.sum(rf * uf), jnp.sum(wf * uf), jnp.sum(uf * uf)])
-    dots_o[...] = jnp.pad(partial[None, :], ((0, 0), (0, LANE - 3)))
+    dots_o[...] = dot_partials_block(partial)
 
 
 def fused_vma_dots_padded(vecs, inv_diag, alpha, beta, *, interpret: bool):
     """Run the kernel on already-padded 2-D (rows, LANE) views.
 
     vecs = (z, q, s, p, x, r, u, w, n, m); returns 9 updated views +
-    per-tile dot partials (tiles, LANE).
+    per-tile dot partials (SUBLANE * tiles, LANE).
     """
     rows = vecs[0].shape[0]
     assert rows % TILE_ROWS == 0, (rows, TILE_ROWS)
@@ -82,9 +82,9 @@ def fused_vma_dots_padded(vecs, inv_diag, alpha, beta, *, interpret: bool):
     vec_spec = pl.BlockSpec((TILE_ROWS, LANE), lambda i: (i, 0))
     scalar_spec = pl.BlockSpec((1,), lambda i: (0,))
 
-    out_shapes = [jax.ShapeDtypeStruct((rows, LANE), dtype) for _ in range(9)]
-    out_shapes.append(jax.ShapeDtypeStruct((tiles, LANE), jnp.float32))
-    out_specs = [vec_spec] * 9 + [pl.BlockSpec((1, LANE), lambda i: (i, 0))]
+    dots_spec, dots_shape = dot_partials_out(tiles)
+    out_shapes = [jax.ShapeDtypeStruct((rows, LANE), dtype) for _ in range(9)] + [dots_shape]
+    out_specs = [vec_spec] * 9 + [dots_spec]
 
     fn = pl.pallas_call(
         _kernel,

@@ -17,7 +17,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..common import LANE, as_2d, ceil_to, interpret_default, pad1d
+from ..common import LANE, as_2d, ceil_to, interpret_default, lane_batched, pad1d, sum_dot_partials
 from .kernel import TILE_ROWS, fused_vma_dots_padded
 
 __all__ = ["fused_vma_dots"]
@@ -35,7 +35,7 @@ def _fused(z, q, s, p, x, r, u, w, n, m, inv_diag, alpha, beta, interpret: bool)
         news = tuple(o.reshape(-1) for o in outs[:9])
     else:
         news = tuple(o.reshape(-1)[:n_elems] for o in outs[:9])
-    dots = outs[9][:, :3].sum(axis=0)
+    dots = sum_dot_partials(outs[9])
     return news + (dots,)
 
 
@@ -47,5 +47,5 @@ def fused_vma_dots(z, q, s, p, x, r, u, w, n, m, inv_diag, alpha, beta, interpre
     """
     if interpret is None:
         interpret = interpret_default()
-    outs = _fused(z, q, s, p, x, r, u, w, n, m, inv_diag, alpha, beta, interpret)
-    return outs
+    run = lane_batched(partial(_fused, interpret=interpret))
+    return run(z, q, s, p, x, r, u, w, n, m, inv_diag, alpha, beta)

@@ -8,9 +8,11 @@ hardware-adaptation noted in DESIGN.md).
 
 Tiling: the grid walks y in 1-D tiles of TILE elements. The x operand is
 passed three times with neighbor index maps (left / center / right block),
-so every static shift within ``bandwidth <= TILE`` reads from the
+so every static shift within ``bandwidth <= TILE`` is a static slice of the
 concatenated 3-tile window held in VMEM. Diagonal data blocks are (n_diags,
-TILE) VMEM tiles.
+TILE) VMEM tiles. TILE is a multiple of 1024 (``common.row_tile``): the
+chip lays 1-D f32 arrays out in 1024-element tiles, and both the block and
+the window concatenation must cover whole ones.
 
 Boundary correctness relies on the DIA convention that ``data[j, i] = 0``
 whenever column ``i + off[j]`` falls outside [0, n) — clamped neighbor
@@ -29,7 +31,7 @@ def _kernel(offsets, tile, dat_ref, xl_ref, xc_ref, xr_ref, y_o):
     xwin = jnp.concatenate([xl_ref[...], xc_ref[...], xr_ref[...]])
     acc = jnp.zeros((tile,), jnp.float32)
     for j, o in enumerate(offsets):
-        seg = jax.lax.dynamic_slice(xwin, (tile + o,), (tile,))
+        seg = xwin[tile + o: 2 * tile + o]
         acc = acc + dat_ref[j, :].astype(jnp.float32) * seg.astype(jnp.float32)
     y_o[...] = acc.astype(y_o.dtype)
 

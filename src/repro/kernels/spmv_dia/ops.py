@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...sparse.formats import DIAMatrix
-from ..common import LANE, ceil_to, interpret_default, pad1d
+from ..common import ceil_to, interpret_default, lane_batched, pad1d, row_tile
 from .kernel import spmv_dia_padded
 
 __all__ = ["spmv_dia_pallas"]
@@ -16,7 +16,7 @@ _DEFAULT_TILE = 4096
 
 
 @partial(jax.jit, static_argnames=("offsets", "tile", "interpret", "out_dtype"))
-def _spmv(data, offsets, x, tile: int, interpret: bool, out_dtype):
+def _spmv(data, x, offsets, tile: int, interpret: bool, out_dtype):
     n = x.shape[0]
     n_pad = ceil_to(n, tile)
     xp = pad1d(x, n_pad)
@@ -30,14 +30,15 @@ def spmv_dia_pallas(A: DIAMatrix, x: jax.Array, tile: int | None = None,
     """y = A @ x for a DIA matrix via the Pallas banded kernel.
 
     ``tile`` must be >= the matrix bandwidth (halo lives in the neighbor
-    blocks); it is auto-raised (LANE-aligned) when needed. ``out_dtype``
+    blocks); it is raised to that and rounded to whole 1-D layout tiles
+    (``common.row_tile``). ``out_dtype``
     (default: x.dtype) lets bf16-storage inputs emit the f32-accumulated
     result without a round trip through bf16.
     """
     if interpret is None:
         interpret = interpret_default()
-    bw = A.bandwidth
-    t = tile or _DEFAULT_TILE
-    t = max(t, ceil_to(bw + 1, LANE))
+    t = row_tile(A.bandwidth, tile or _DEFAULT_TILE)
     out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else None
-    return _spmv(A.data, A.offsets, x, t, interpret, out_dtype)
+    run = lane_batched(partial(_spmv, offsets=A.offsets, tile=t, interpret=interpret,
+                               out_dtype=out_dtype))
+    return run(A.data, x)

@@ -21,15 +21,34 @@ it on startup.
 LD_PRELOAD (tcmalloc) cannot take effect from inside a running process —
 :func:`tcmalloc_note` returns the export line to put in a wrapper script
 when a system tcmalloc exists and none is preloaded.
+
+:func:`enable_compile_cache` turns on JAX's persistent compilation cache
+for the entry points (``chip_smoke.py``, ``launch/solve.py``,
+``launch/serve.py``, ``benchmarks/run.py``) before they compile anything.
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing is
+overridden; otherwise the cache lives at one fixed path in the checkout,
+``<repo>/.jax_cache`` (the path is part of the cache key, so it never
+moves). Git ignores that directory.
 """
 from __future__ import annotations
 
 import os
 import sys
 import warnings
+from pathlib import Path
 from typing import Dict, Mapping, Optional, Sequence
 
-__all__ = ["apply_env", "tcmalloc_note", "DEFAULT_ENV", "TCMALLOC_PATHS"]
+__all__ = [
+    "apply_env",
+    "compile_cache_dir",
+    "enable_compile_cache",
+    "tcmalloc_note",
+    "DEFAULT_ENV",
+    "TCMALLOC_PATHS",
+]
+
+# <repo>/src/repro/launch/env.py -> <repo>/.jax_cache
+_REPO_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
 # vars applied when (and only when) absent — the SNIPPETS run.sh set
 DEFAULT_ENV: Dict[str, str] = {
@@ -113,3 +132,23 @@ def apply_env(
         env["XLA_FLAGS"] = " ".join(([current] if current else []) + new_flags)
         applied["XLA_FLAGS"] = env["XLA_FLAGS"]
     return applied
+
+
+def compile_cache_dir(env: Mapping[str, str] = os.environ) -> str:
+    """Where the persistent compilation cache lives: the operator's
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``."""
+    return env.get("JAX_COMPILATION_CACHE_DIR") or _REPO_CACHE_DIR
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on; returns its directory.
+
+    Sets JAX's cache directory only when ``JAX_COMPILATION_CACHE_DIR`` is
+    unset (JAX reads that variable itself). Call before compiling.
+    """
+    import jax
+
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import jax
 
-from ..compat import AxisType, make_mesh
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_solver_mesh_from", "DATA_AXES", "MODEL_AXIS"]
 
@@ -32,7 +32,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import"
         )
-    return make_mesh(shape, axes, devices=devs[:n], axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, devices=devs[:n], axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_solver_mesh_from(mesh) -> "jax.sharding.Mesh":

@@ -22,7 +22,7 @@ import sys
 
 # env hygiene must precede any jax import — keep this module jax-free
 # until main() has called apply_env()
-from .env import apply_env, tcmalloc_note
+from .env import apply_env, enable_compile_cache, tcmalloc_note
 
 
 def main(argv=None):
@@ -59,6 +59,7 @@ def main(argv=None):
     if note:
         print(f"env note: {note}")
 
+    enable_compile_cache()
     import jax.numpy as jnp
 
     import repro.obs as obs

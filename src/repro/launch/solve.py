@@ -25,6 +25,8 @@ if "jax" not in sys.modules:
 
 import jax.numpy as jnp
 
+from .env import enable_compile_cache
+
 from .. import plan, solver_names
 from ..sparse import poisson7, poisson27, poisson125, spmv, synthetic_spd_dia, table1_matrix
 
@@ -66,6 +68,7 @@ def main(argv=None):
     ap.add_argument("--rhs", type=int, default=1,
                     help="number of right-hand sides served through the one plan")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     A = build_matrix(args.matrix)
     xstar = jnp.ones((A.n,)) / jnp.sqrt(A.n)

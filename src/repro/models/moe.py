@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from .common import ParamSpec, current_mesh, shard_hint
 
 __all__ = ["moe_params", "moe_ffn", "moe_ffn_sharded", "moe_capacity"]
@@ -168,7 +167,7 @@ def moe_ffn_sharded(p: dict, x3: jax.Array, top_k: int, capacity_factor: float =
         return y.reshape(B_loc, T, d), aux
 
     spec_x = P(batch_axes if len(batch_axes) > 1 else batch_axes[0], None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec_x, P(None, None), P("model", None, None), P("model", None, None), P("model", None, None)),

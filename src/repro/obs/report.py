@@ -5,7 +5,7 @@ decomposition are justified by wall-clock and a performance model — so a
 solve result here carries its evidence: the trimmed convergence curve,
 iterations-to-tolerance, time-to-solution, kernel launches per iteration
 (from the jaxpr census in ``kernels.common``), the structural bytes-moved
-model and achieved GB/s against the ``launch/roofline`` HBM peak,
+model and achieved GB/s against the device's published HBM peak,
 residual-replacement events, plan-cache traffic and an environment
 fingerprint that makes trajectory points comparable across runs.
 
@@ -29,6 +29,8 @@ __all__ = [
     "iterations_from_history",
     "env_fingerprint",
     "structural_bytes_per_elem",
+    "HBM_PEAK_BYTES_PER_S",
+    "hbm_peak",
     "plan_launches_per_iteration",
     "SolveReport",
     "solve_report",
@@ -160,6 +162,17 @@ def plan_launches_per_iteration(plan, b, primitive: str = "pallas_call") -> Opti
     return None if n < 0 else int(n)
 
 
+# Published HBM bandwidth per chip, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite": 819 GB/s — Google Cloud documentation, "TPU v5e".
+HBM_PEAK_BYTES_PER_S: Dict[str, float] = {"TPU v5 lite": 819e9}
+
+
+def hbm_peak(device_kind: str) -> Optional[float]:
+    """Published HBM bytes/s of ``device_kind``; None for a device the
+    table does not hold (CPU included) — its share is "not measured"."""
+    return HBM_PEAK_BYTES_PER_S.get(device_kind)
+
+
 # ---------------------------------------------------------------------------
 # the report
 # ---------------------------------------------------------------------------
@@ -228,9 +241,11 @@ class SolveReport:
         if self.launches_per_iter is not None:
             lines.append(f"  launches    : {self.launches_per_iter} kernel(s)/iter (jaxpr census)")
         if self.achieved_gbs is not None:
+            share = ("not measured" if self.frac_of_hbm_peak is None
+                     else f"{self.frac_of_hbm_peak:.1%}")
             lines.append(
                 f"  bandwidth   : {self.achieved_gbs:.2f} GB/s achieved "
-                f"({self.frac_of_hbm_peak:.1%} of HBM roofline, structural model)"
+                f"({share} of HBM roofline, structural model)"
             )
         if self.replace_every:
             lines.append(
@@ -255,7 +270,7 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
     the honest end-to-end time but refuses to derive per-iteration time or
     achieved bandwidth from it.
     """
-    from ..launch.roofline import HW
+    import jax
 
     desc = plan.describe()
     iterations = int(np.asarray(result.iterations).max())
@@ -279,7 +294,8 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
         time_per_iter = elapsed_s / iterations
         if est_bytes is not None:
             achieved = est_bytes / time_per_iter / 1e9
-            frac = achieved / (HW["hbm_bw"] / 1e9)
+            peak = hbm_peak(jax.devices()[0].device_kind)
+            frac = None if peak is None else achieved / (peak / 1e9)
 
     replace_every = int(desc.get("replace_every") or 0)
     rr_events = iterations // replace_every if replace_every > 0 else 0

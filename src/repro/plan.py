@@ -59,6 +59,7 @@ from .core.perfmodel import decompose
 from .core.preconditioners import IdentityPC, JacobiPC
 from .core.types import SolveResult
 from .sparse import balanced_rows, shard_dia, shard_vector, spmv, unshard_vector
+from .sparse.partition import row_sharding
 from .sparse.formats import DIAMatrix
 
 __all__ = [
@@ -309,7 +310,8 @@ class SolverPlan:
         self.bounds = tuple(int(x) for x in np.asarray(bounds))
         with _span("plan.shard"):
             self.mesh = mesh if mesh is not None else make_solver_mesh(shards, sub=sub)
-            self.sharded = shard_dia(A, bounds)  # the reusable operator handle
+            # the reusable operator handle, each row block on its own device
+            self.sharded = shard_dia(A, bounds, mesh=self.mesh)
         # every knob that changes the compiled program goes in here — this
         # dict is what describe() reports, and the same knobs (as user
         # kwargs) are what _plan_key freezes, so pl2/pl3/h4/sub/replace
@@ -335,12 +337,15 @@ class SolverPlan:
         self.pipeline_depth = runner.pipeline_depth
         self.reducer = runner.reduce_name
         self.spmv_strategy = runner.spmv_name
-        inv_sh = shard_vector(inv_diag, bounds)
+        mesh = self.mesh
+        inv_sh = jax.device_put(shard_vector(inv_diag, bounds), row_sharding(mesh, 2))
         self._inv_sh = inv_sh
         bounds_arr = self.bounds
 
         def _solve_rhs(rhs, atol, rtol) -> SolveResult:
-            res = runner(shard_vector(rhs, bounds_arr), inv_sh, atol, rtol)
+            b_sh = jax.lax.with_sharding_constraint(
+                shard_vector(rhs, bounds_arr), row_sharding(mesh, 2))
+            res = runner(b_sh, inv_sh, atol, rtol)
             return SolveResult(
                 x=unshard_vector(res.x, bounds_arr), iterations=res.iterations,
                 residual_norm=res.residual_norm, converged=res.converged,
@@ -502,7 +507,9 @@ class SolverPlan:
         def _solve_rhs_batch(B, atol, rtol) -> SolveResult:
             from .sparse import shard_vectors, unshard_vectors
 
-            res = runner(shard_vectors(B, bounds), inv_sh, atol, rtol)
+            B_sh = jax.lax.with_sharding_constraint(
+                shard_vectors(B, bounds), row_sharding(self.mesh, 3))
+            res = runner(B_sh, inv_sh, atol, rtol)
             return SolveResult(
                 x=unshard_vectors(res.x, bounds), iterations=res.iterations,
                 residual_norm=res.residual_norm, converged=res.converged,
@@ -530,6 +537,11 @@ class SolverPlan:
         self._batched_runners[(k, with_x0)] = jitted
         return jitted
 
+    def _batched_single(self):
+        if self._run_batched is None:
+            self._run_batched = jax.jit(jax.vmap(self._inner, in_axes=(0, 0, None, None)))
+        return self._run_batched
+
     def _execute_batched(self, B, x0, atol, rtol) -> SolveResult:
         atol, rtol = self._tols(atol, rtol)
         if self.distributed:
@@ -537,11 +549,23 @@ class SolverPlan:
             if x0 is None:
                 return run(B, atol, rtol)
             return run(B, x0, atol, rtol)
-        if self._run_batched is None:
-            self._run_batched = jax.jit(jax.vmap(self._inner, in_axes=(0, 0, None, None)))
         if x0 is None:
             x0 = jnp.zeros_like(B)
-        return self._run_batched(B, x0, atol, rtol)
+        return self._batched_single()(B, x0, atol, rtol)
+
+    def lower(self, b) -> "jax.stages.Lowered":
+        """The program ``solve(b)`` (1-D ``b``) or ``solve_batched(b)``
+        (2-D ``b``, no ``x0``) runs, lowered for inspection — e.g.
+        ``plan.lower(b).compile().as_text()``. A program that already ran
+        with these shapes is not traced again."""
+        atol, rtol = self._tols(None, None)
+        if b.ndim == 2:
+            if self.distributed:
+                return self._batched_distributed(int(b.shape[0]), False).lower(b, atol, rtol)
+            return self._batched_single().lower(b, jnp.zeros_like(b), atol, rtol)
+        if self.distributed:
+            return self._run.lower(b, atol, rtol)
+        return self._run.lower(b, jnp.zeros_like(b), atol, rtol)
 
     def describe(self) -> dict:
         """Introspection: what this plan pinned at setup."""

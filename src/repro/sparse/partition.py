@@ -28,6 +28,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from .formats import DIAMatrix
 
@@ -35,6 +36,7 @@ __all__ = [
     "balanced_rows",
     "balanced_nnz",
     "ShardedDIA",
+    "row_sharding",
     "shard_dia",
     "shard_vector",
     "unshard_vector",
@@ -111,8 +113,21 @@ class ShardedDIA:
         return self.data[:, j, :]  # (P, rows_max)
 
 
-def shard_dia(dia: DIAMatrix, boundaries: np.ndarray) -> ShardedDIA:
-    """Split a DIA matrix into padded row blocks along ``boundaries``."""
+def row_sharding(mesh, ndim: int) -> NamedSharding:
+    """Leading (shard) axis split over every mesh axis, the rest whole —
+    the layout in which the shard_map'd solver takes its row blocks."""
+    names = tuple(mesh.axis_names)
+    axis = names[0] if len(names) == 1 else names
+    return NamedSharding(mesh, PartitionSpec(axis, *([None] * (ndim - 1))))
+
+
+def shard_dia(dia: DIAMatrix, boundaries: np.ndarray, mesh=None) -> ShardedDIA:
+    """Split a DIA matrix into padded row blocks along ``boundaries``.
+
+    With ``mesh``, each block goes straight from host memory to its own
+    device (:func:`row_sharding`); without, the stack lands on the
+    default device.
+    """
     P = len(boundaries) - 1
     sizes = np.diff(boundaries)
     rows_max = int(sizes.max())
@@ -134,9 +149,15 @@ def shard_dia(dia: DIAMatrix, boundaries: np.ndarray) -> ShardedDIA:
         lo, hi = int(boundaries[p]), int(boundaries[p + 1])
         out[p, :, : hi - lo] = data_np[:, lo:hi]
         out[p, j0, hi - lo :] = 1.0  # identity padding rows
+    rows_valid = sizes.astype(np.int32)
+    if mesh is None:
+        data, rows_valid = jnp.asarray(out), jnp.asarray(rows_valid)
+    else:
+        data = jax.device_put(out, row_sharding(mesh, out.ndim))
+        rows_valid = jax.device_put(rows_valid, row_sharding(mesh, 1))
     return ShardedDIA(
-        data=jnp.asarray(out),
-        rows_valid=jnp.asarray(sizes, dtype=jnp.int32),
+        data=data,
+        rows_valid=rows_valid,
         offsets=dia.offsets,
         n=dia.n,
         rows_max=rows_max,

@@ -7,8 +7,8 @@ matrix (engine x format):
     engine      DIAMatrix            BellMatrix          CSRMatrix
     ---------   ------------------   -----------------   ------------------
     "jnp"       spmv_dia (shifts)    spmv_bell (gather)  spmv_csr (scatter)
-    "pallas"    kernels.spmv_dia     kernels.spmv_bell   — (jnp fallback)
-                (banded, 3-window)   (B-ELL, VMEM x)
+    "pallas"    kernels.spmv_dia     — (jnp fallback)    — (jnp fallback)
+                (banded, 3-window)
     "segsum"    — (jnp fallback)     — (jnp fallback)    spmv_csr_segsum
     "bf16"      spmv_dia_bf16        — (jnp fallback)    — (jnp fallback)
                 (bf16 storage,
@@ -16,6 +16,11 @@ matrix (engine x format):
 
     jax.Array            -> A @ x (dense "jnp" fallback)
     object with .matvec  -> protocol fallback (matrix-free FunctionOperator)
+
+BellMatrix runs on "jnp" on every backend: the B-ELL kernel
+(``kernels.spmv_bell``) gathers ``x[cols]`` in VMEM, which the TPU
+compiler refuses ("Only 2D gather is supported"), so it is not registered
+here until a BELL kernel compiles for the chip.
 
 ``engine="auto"`` resolution (see :func:`resolve_engine`): "pallas" on
 TPU when registered for the format; otherwise the fastest registered
@@ -135,15 +140,6 @@ def _spmv_dia_pallas(A: DIAMatrix, x: jax.Array) -> jax.Array:
     return spmv_dia_pallas(A, x)
 
 
-def _spmv_bell_pallas(A: BellMatrix, x: jax.Array) -> jax.Array:
-    from ..kernels.spmv_bell import spmv_bell_pallas
-    from ..kernels.spmv_bell.ops import _VMEM_ROWS_LIMIT
-
-    if A.n > _VMEM_ROWS_LIMIT:  # kernel keeps x resident in VMEM
-        return spmv_bell(A, x)
-    return spmv_bell_pallas(A, x)
-
-
 # (matrix type) -> (engine name) -> fn(A, x) -> y
 _REGISTRY: Dict[type, Dict[str, Callable]] = {}
 
@@ -167,7 +163,6 @@ register_spmv(DIAMatrix, "jnp", spmv_dia)
 register_spmv(DIAMatrix, "pallas", _spmv_dia_pallas)
 register_spmv(DIAMatrix, "bf16", spmv_dia_bf16)
 register_spmv(BellMatrix, "jnp", spmv_bell)
-register_spmv(BellMatrix, "pallas", _spmv_bell_pallas)
 register_spmv(CSRMatrix, "jnp", spmv_csr)
 register_spmv(CSRMatrix, "segsum", spmv_csr_segsum)
 

@@ -49,7 +49,8 @@ def _padded_operands(A, tile, dtype=jnp.float32, seed=0):
 
 
 class TestKernelParity:
-    @pytest.mark.parametrize("gen", [lambda: poisson27(7), lambda: synthetic_spd_dia(500, 9.0, seed=4)])
+    # sizes above one 1024-element row tile, so the halo paths run
+    @pytest.mark.parametrize("gen", [lambda: poisson27(12), lambda: synthetic_spd_dia(1500, 9.0, seed=4)])
     def test_matches_ref(self, gen):
         A = gen()
         t, n_pad, data, vecs, inv = _padded_operands(A, TILE)

@@ -102,6 +102,53 @@ class TestSpmvDia:
         np.testing.assert_allclose(y_k, y_r, rtol=1e-4, atol=1e-4)
 
 
+class TestRowTile:
+    """1-D row tiles are whole 1024-element TPU layout tiles, >= bandwidth + 1."""
+
+    def test_hpcg_104_tile(self):
+        from repro.core.pipecg import _padded_tile
+        from repro.kernels import fused_iter_tile
+
+        # 27-point stencil on a 104^3 grid: bandwidth 104^2 + 104 + 1
+        assert _padded_tile("pallas", 10_921, None) == 11_264
+        assert fused_iter_tile(10_921) == 11_264
+
+    @pytest.mark.parametrize("bandwidth,tile", [(1, None), (3_962, None), (4_095, None),
+                                                (4_096, None), (27_391, None), (10, 256)])
+    def test_rule(self, bandwidth, tile):
+        from repro.core.pipecg import _padded_length, _padded_tile
+        from repro.kernels import fused_iter_tile
+
+        for t in (_padded_tile("pallas", bandwidth, tile), fused_iter_tile(bandwidth, tile)):
+            assert t % 1024 == 0 and t >= bandwidth + 1 and t >= (tile or 4096)
+        t = _padded_tile("pallas", bandwidth, tile)
+        n_pad = _padded_length(100_003, t)
+        assert n_pad >= 100_003 and n_pad % t == 0 and n_pad % 4096 == 0
+
+
+class TestVmap:
+    """Under vmap the kernels run once per lane: same values as unbatched."""
+
+    def test_spmv_dia(self):
+        A = poisson27(12)
+        X = jnp.stack([_rand(A.n, jnp.float32, seed=s) for s in range(3)])
+        y = np.asarray(jax.vmap(lambda x: spmv_dia_pallas(A, x))(X))
+        for i in range(3):
+            np.testing.assert_array_equal(y[i], np.asarray(spmv_dia_pallas(A, X[i])))
+
+    def test_fused_vma_batched_scalars(self):
+        n = 3000
+        vecs = [jnp.stack([_rand(n, jnp.float32, seed=10 * k + i) for k in range(2)])
+                for i in range(10)]
+        inv = jnp.ones((n,))
+        alpha, beta = jnp.array([0.3, 0.6]), jnp.array([0.2, 0.9])
+        outs = jax.vmap(fused_vma_dots, in_axes=(0,) * 10 + (None, 0, 0))(*vecs, inv, alpha, beta)
+        for k in range(2):
+            ref = fused_vma_dots(*[v[k] for v in vecs], inv, alpha[k], beta[k])
+            for got, want in zip(outs, ref):
+                np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want), rtol=1e-6)
+
+
 class TestSpmvBell:
     @pytest.mark.parametrize("n", [64, 300, 2048])
     @pytest.mark.parametrize("dtype", DTYPES)

@@ -17,7 +17,7 @@ import sys
 
 import pytest
 
-from repro.launch.env import DEFAULT_ENV, apply_env, tcmalloc_note
+from repro.launch.env import DEFAULT_ENV, apply_env, compile_cache_dir, tcmalloc_note
 
 
 class TestApplyEnv:
@@ -91,3 +91,17 @@ class TestImportOrder:
         )
         assert proc.returncode == 0, proc.stderr
         assert "OK" in proc.stdout
+
+
+class TestCompileCache:
+    def test_external_dir_kept(self):
+        env = {"JAX_COMPILATION_CACHE_DIR": "/srv/jax-cache"}
+        assert compile_cache_dir(env) == "/srv/jax-cache"
+
+    def test_fixed_in_checkout_path(self):
+        first, second = compile_cache_dir({}), compile_cache_dir({})
+        assert first == second
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()

@@ -13,13 +13,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from dataclasses import replace
-from repro.compat import AxisType, make_mesh
+from jax.sharding import AxisType
 from repro.configs import get_config, reduced
 from repro.models import build_model
 from repro.models.common import use_sharding_rules
 from repro.launch.sharding import DEFAULT_RULES, make_resolver
 
-mesh = make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,)*2)
 cfg = reduced(get_config("{arch}"))
 cfg = replace(cfg, moe_capacity_factor=float(cfg.n_experts))
 api = build_model(cfg)

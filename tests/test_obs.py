@@ -240,7 +240,8 @@ class TestSolveReport:
         # is the one pallas_call in the loop body
         assert rep.launches_per_iter == 1
         assert rep.achieved_gbs is not None and rep.achieved_gbs > 0
-        assert 0 < rep.frac_of_hbm_peak < 1
+        # no published HBM peak for this device kind on CPU: not measured
+        assert rep.frac_of_hbm_peak is None
         assert rep.env["backend"] == jax.default_backend()
         assert rep.trace_count == p.trace_count
         s = rep.summary()

@@ -121,9 +121,7 @@ class TestEquivalence:
 
     def test_solutions_match_f64(self):
         """Under float64 the algebraic equivalence is near-exact."""
-        from repro.compat import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             A = synthetic_spd_dia(200, 7.0, seed=13, dtype=jnp.float64)
             xstar = jnp.ones((200,), jnp.float64) / jnp.sqrt(200.0)
             b = spmv(A, xstar)

@@ -24,6 +24,7 @@ from repro.sparse import (
     csr_from_dia,
     poisson27,
     register_spmv,
+    resolve_engine,
     spmv,
     spmv_engines,
     synthetic_spd_dia,
@@ -47,10 +48,15 @@ class TestSpmvDispatch:
         np.testing.assert_allclose(y_p, y_j, rtol=1e-5, atol=1e-4)
 
     def test_bell_pallas_matches_jnp(self):
+        from repro.kernels import spmv_bell_pallas
+
         A = bell_from_csr(csr_from_dia(poisson27(6)))
         x = jnp.asarray(np.random.default_rng(1).normal(size=(A.n,)), jnp.float32)
         y_j = np.asarray(spmv(A, x, engine="jnp"), np.float64)
-        y_p = np.asarray(spmv(A, x, engine="pallas"), np.float64)
+        # the B-ELL kernel does not compile for TPU: BELL runs on jnp on
+        # every backend, whichever engine is asked for
+        assert resolve_engine(A, "pallas") == resolve_engine(A, "auto") == "jnp"
+        y_p = np.asarray(spmv_bell_pallas(A, x), np.float64)  # interpret-mode kernel
         np.testing.assert_allclose(y_p, y_j, rtol=1e-5, atol=1e-4)
 
     def test_dense_fallback(self):
@@ -181,10 +187,11 @@ class TestCrossStrategy:
 
 class TestCompat:
     def test_shim_exports(self):
-        from repro.compat import AxisType, make_mesh, shard_map
+        # the installed JAX provides these directly; the repo has no shims
+        from jax.sharding import AxisType
 
-        assert callable(shard_map)
+        assert callable(jax.shard_map)
         assert hasattr(AxisType, "Auto")
-        mesh = make_mesh((1,), ("x",), devices=jax.devices()[:1],
-                         axis_types=(AxisType.Auto,))
+        mesh = jax.make_mesh((1,), ("x",), devices=jax.devices()[:1],
+                             axis_types=(AxisType.Auto,))
         assert tuple(mesh.axis_names) == ("x",)
