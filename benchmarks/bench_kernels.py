@@ -21,10 +21,9 @@ import jax.numpy as jnp
 from repro.launch.roofline import analyze_hlo
 from repro.kernels import fused_vma_dots, fused_vma_dots_ref
 from repro.kernels.common import launches_per_iteration
-from repro.obs import structural_bytes_per_elem
-from repro.obs.report import hbm_peak
 
-from .common import bench_record, emit, seed_key, timeit_call, write_bench_json
+from .common import (bench_record, emit, hbm_peak, seed_key, structural_bytes_per_elem,
+                     timeit_call, write_bench_json)
 
 
 def iteration_cores(grid: int = 24, maxiter: int = 20, json_path: str | None = None):

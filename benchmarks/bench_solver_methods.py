@@ -23,10 +23,10 @@ import argparse
 import jax.numpy as jnp
 
 import repro
-from repro.obs import plan_launches_per_iteration, structural_bytes_per_elem
+from repro.obs import plan_launches_per_iteration
 from repro.sparse import poisson27, spmv, table1_matrix
 
-from .common import bench_record, emit, timeit_call, write_bench_json
+from .common import bench_record, emit, structural_bytes_per_elem, timeit_call, write_bench_json
 
 MATRICES = [
     ("bcsstk15", lambda: table1_matrix("bcsstk15", scale=1.0)),       # N=3948

@@ -17,6 +17,7 @@ from __future__ import annotations
 import json
 import time
 import zlib
+from typing import Optional
 
 import jax
 
@@ -31,6 +32,37 @@ def seed_key(name: str, i: int = 0):
     in every process, which is what makes trajectory points comparable.
     """
     return jax.random.fold_in(jax.random.PRNGKey(zlib.crc32(name.encode()) & 0x7FFFFFFF), i)
+
+
+def structural_bytes_per_elem(core: str, n_diags: int, elem_bytes: int = 4) -> Optional[float]:
+    """Per-iteration HBM bytes/row each core moves BY CONSTRUCTION.
+
+    jnp        — separate passes: SPMV (band + x + y) + 8 triads
+                 (2 reads, 1 write each) + PC (3) + 3 dots (2 reads each).
+    pallas     — SPMV kernel (band + x + y) + one fused VMA kernel
+                 (11 reads + 9 writes).
+    fused_iter — ONE kernel: band + m + 8 state vecs + inv_diag reads,
+                 9 vector writes (dot partials are noise).
+
+    Returns None for cores the model does not cover (plug-ins).
+    """
+    vec = {
+        "jnp": (n_diags + 2) + 8 * 3 + 3 + 3 * 2,
+        "pallas": (n_diags + 2) + (11 + 9),
+        "fused_iter": n_diags + 10 + 9,
+    }.get(core)
+    return None if vec is None else vec * float(elem_bytes)
+
+
+# Published HBM bandwidth per chip, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite": 819 GB/s — Google Cloud documentation, "TPU v5e".
+HBM_PEAK_BYTES_PER_S = {"TPU v5 lite": 819e9}
+
+
+def hbm_peak(device_kind: str) -> Optional[float]:
+    """Published HBM bytes/s of ``device_kind``; None for a device the
+    table does not hold (CPU included) — its share is "not measured"."""
+    return HBM_PEAK_BYTES_PER_S.get(device_kind)
 
 
 def timeit_call(fn, *args, warmup: int = 2, iters: int = 5) -> float:

@@ -36,5 +36,6 @@ def fused_dots_padded(r, u, w, *, interpret: bool):
         out_specs=dots_spec,
         out_shape=dots_shape,
         interpret=interpret,
+        name="fused_dot",
     )
     return fn(r, u, w)

@@ -127,6 +127,7 @@ def fused_iter_padded(data, offsets, vecs, inv_diag, alpha, beta, *, tile: int, 
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="fused_iter",
     )
     alpha = jnp.asarray(alpha, jnp.float32).reshape(1)
     beta = jnp.asarray(beta, jnp.float32).reshape(1)

@@ -93,6 +93,7 @@ def fused_vma_dots_padded(vecs, inv_diag, alpha, beta, *, interpret: bool):
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="fused_vma",
     )
     alpha = jnp.asarray(alpha, jnp.float32).reshape(1)
     beta = jnp.asarray(beta, jnp.float32).reshape(1)

@@ -63,5 +63,6 @@ def spmv_dia_padded(data, offsets: tuple[int, ...], x, *, tile: int, interpret: 
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), out_dtype or x.dtype),
         interpret=interpret,
+        name="spmv_dia",
     )
     return fn(data, x, x, x)

@@ -4,10 +4,11 @@ The paper's contribution is *measured* — overlap, fusion and the CPU/GPU
 decomposition are justified by wall-clock and a performance model — so a
 solve result here carries its evidence: the trimmed convergence curve,
 iterations-to-tolerance, time-to-solution, kernel launches per iteration
-(from the jaxpr census in ``kernels.common``), the structural bytes-moved
-model and achieved GB/s against the device's published HBM peak,
-residual-replacement events, plan-cache traffic and an environment
-fingerprint that makes trajectory points comparable across runs.
+(from the jaxpr census in ``kernels.common``), residual-replacement
+events, plan-cache traffic and an environment fingerprint that makes
+trajectory points comparable across runs. Bandwidth is not here: a share
+of the HBM roofline needs device time from a profiler trace, which the
+benchmark under ``bench/`` reads.
 
 ``SolverPlan.solve`` builds one of these automatically when observability
 is enabled (``plan.last_report``); :func:`solve_report` is the manual
@@ -20,7 +21,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -28,9 +29,6 @@ __all__ = [
     "convergence_curve",
     "iterations_from_history",
     "env_fingerprint",
-    "structural_bytes_per_elem",
-    "HBM_PEAK_BYTES_PER_S",
-    "hbm_peak",
     "plan_launches_per_iteration",
     "SolveReport",
     "solve_report",
@@ -113,28 +111,8 @@ def comparable_env(a: Dict[str, Any], b: Dict[str, Any]) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# structural traffic model + census
+# launch census
 # ---------------------------------------------------------------------------
-
-def structural_bytes_per_elem(core: str, n_diags: int, elem_bytes: int = 4) -> Optional[float]:
-    """Per-iteration HBM bytes/row each core moves BY CONSTRUCTION.
-
-    jnp        — separate passes: SPMV (band + x + y) + 8 triads
-                 (2 reads, 1 write each) + PC (3) + 3 dots (2 reads each).
-    pallas     — SPMV kernel (band + x + y) + one fused VMA kernel
-                 (11 reads + 9 writes).
-    fused_iter — ONE kernel: band + m + 8 state vecs + inv_diag reads,
-                 9 vector writes (dot partials are noise).
-
-    Returns None for cores the model does not cover (plug-ins).
-    """
-    vec = {
-        "jnp": (n_diags + 2) + 8 * 3 + 3 + 3 * 2,
-        "pallas": (n_diags + 2) + (11 + 9),
-        "fused_iter": n_diags + 10 + 9,
-    }.get(core)
-    return None if vec is None else vec * float(elem_bytes)
-
 
 def plan_launches_per_iteration(plan, b, primitive: str = "pallas_call") -> Optional[int]:
     """Census ``primitive`` occurrences in one iteration of a plan's loop.
@@ -160,17 +138,6 @@ def plan_launches_per_iteration(plan, b, primitive: str = "pallas_call") -> Opti
     except Exception:
         return None
     return None if n < 0 else int(n)
-
-
-# Published HBM bandwidth per chip, keyed by ``jax.Device.device_kind``.
-# "TPU v5 lite": 819 GB/s — Google Cloud documentation, "TPU v5e".
-HBM_PEAK_BYTES_PER_S: Dict[str, float] = {"TPU v5 lite": 819e9}
-
-
-def hbm_peak(device_kind: str) -> Optional[float]:
-    """Published HBM bytes/s of ``device_kind``; None for a device the
-    table does not hold (CPU included) — its share is "not measured"."""
-    return HBM_PEAK_BYTES_PER_S.get(device_kind)
 
 
 # ---------------------------------------------------------------------------
@@ -199,9 +166,6 @@ class SolveReport:
     cold_start: bool  # this solve traced/compiled: wall time is not steady-state
     time_per_iter_s: Optional[float]
     launches_per_iter: Optional[int]
-    est_bytes_per_iter: Optional[float]
-    achieved_gbs: Optional[float]
-    frac_of_hbm_peak: Optional[float]
     # numerics safety net
     replace_every: int
     rr_events: int
@@ -240,13 +204,6 @@ class SolveReport:
             lines.append(f"  time        : {self.time_s*1e3:.3f} ms{per}{cold}")
         if self.launches_per_iter is not None:
             lines.append(f"  launches    : {self.launches_per_iter} kernel(s)/iter (jaxpr census)")
-        if self.achieved_gbs is not None:
-            share = ("not measured" if self.frac_of_hbm_peak is None
-                     else f"{self.frac_of_hbm_peak:.1%}")
-            lines.append(
-                f"  bandwidth   : {self.achieved_gbs:.2f} GB/s achieved "
-                f"({share} of HBM roofline, structural model)"
-            )
         if self.replace_every:
             lines.append(
                 f"  resid-repl  : every {self.replace_every} iters -> {self.rr_events} event(s)"
@@ -267,11 +224,9 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
     shape — the census traces, it does not execute); ``launches`` passes
     an already-censused count instead (plans cache theirs). ``cold_start``
     marks a solve whose wall time includes trace/compile: the report keeps
-    the honest end-to-end time but refuses to derive per-iteration time or
-    achieved bandwidth from it.
+    the honest end-to-end time but refuses to derive per-iteration time
+    from it.
     """
-    import jax
-
     desc = plan.describe()
     iterations = int(np.asarray(result.iterations).max())
     curve = convergence_curve(result)
@@ -282,20 +237,9 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
     if launches is None and b is not None:
         launches = plan_launches_per_iteration(plan, b)
 
-    n = desc.get("n")
-    est_bpe = None
-    if core is not None and hasattr(plan.A, "data"):
-        elem = int(np.dtype(np.asarray(plan.A.data).dtype).itemsize)
-        est_bpe = structural_bytes_per_elem(core, int(plan.A.data.shape[0]), elem)
-    est_bytes = None if (est_bpe is None or n is None) else est_bpe * n
-
-    time_per_iter = achieved = frac = None
+    time_per_iter = None
     if elapsed_s is not None and iterations > 0 and not cold_start:
         time_per_iter = elapsed_s / iterations
-        if est_bytes is not None:
-            achieved = est_bytes / time_per_iter / 1e9
-            peak = hbm_peak(jax.devices()[0].device_kind)
-            frac = None if peak is None else achieved / (peak / 1e9)
 
     replace_every = int(desc.get("replace_every") or 0)
     rr_events = iterations // replace_every if replace_every > 0 else 0
@@ -307,7 +251,7 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
         engine=desc.get("engine", "?"),
         core=core,
         operator=desc.get("operator", type(plan.A).__name__),
-        n=n,
+        n=desc.get("n"),
         dtype=desc.get("dtype", "?"),
         distributed=bool(desc.get("distributed", False)),
         iterations=iterations,
@@ -318,9 +262,6 @@ def solve_report(plan, result, *, elapsed_s: Optional[float] = None, b=None,
         cold_start=cold_start,
         time_per_iter_s=time_per_iter,
         launches_per_iter=launches,
-        est_bytes_per_iter=est_bytes,
-        achieved_gbs=achieved,
-        frac_of_hbm_peak=frac,
         replace_every=replace_every,
         rr_events=rr_events,
         trace_count=plan.trace_count,

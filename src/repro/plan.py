@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import hashlib
 import inspect
+import itertools
 import sys as _sys
 import time
 from collections import OrderedDict
@@ -150,6 +151,8 @@ def _solve_pipecg(A, b, *, M, x0, atol, rtol, maxiter, engine,
 
 SolverFn = Callable[..., SolveResult]
 
+_PLAN_IDS = itertools.count(1)  # per-process plan number, the spans' ``plan``
+
 _SOLVERS: Dict[str, SolverFn] = {
     "pcg": _solve_pcg,
     "chronopoulos": _solve_chronopoulos,
@@ -204,6 +207,8 @@ class SolverPlan:
         self.n = int(A.shape[0]) if hasattr(A, "shape") else None
         self.distributed = distributed
         self._traces = 0
+        self._id = next(_PLAN_IDS)
+        self._call_ids = itertools.count(1)  # numbers solve calls, the spans' ``call``
         self._run = None
         self._run_batched = None
         self._run_x0 = None
@@ -254,9 +259,7 @@ class SolverPlan:
         self._core = call_kwargs.get("core")
 
         def _inner(b, x0, atol, rtol):
-            self._traces += 1  # runs at trace time only
-            _metrics.counter("plan.traces").inc()
-            with _trace_scope(f"solve.{self.method}"):
+            with self._traced(), _trace_scope(f"solve.{self.method}"):
                 return fn(A, b, M=M, x0=x0, atol=atol, rtol=rtol,
                           maxiter=maxiter, engine=engine, **call_kwargs)
 
@@ -353,16 +356,14 @@ class SolverPlan:
             )
 
         def _inner0(b, atol, rtol):
-            self._traces += 1
-            _metrics.counter("plan.traces").inc()
-            return _solve_rhs(b, atol, rtol)
+            with self._traced():
+                return _solve_rhs(b, atol, rtol)
 
         def _inner_x0(b, x0, atol, rtol):
             # nonzero warm start: solve the shifted system A d = b - A x0,
             # then x = x0 + d (no host sync, no x0==0 guard needed)
-            self._traces += 1
-            _metrics.counter("plan.traces").inc()
-            res = _solve_rhs(b - spmv(A, x0), atol, rtol)
+            with self._traced():
+                res = _solve_rhs(b - spmv(A, x0), atol, rtol)
             return SolveResult(
                 x=x0 + res.x, iterations=res.iterations,
                 residual_norm=res.residual_norm, converged=res.converged,
@@ -379,6 +380,18 @@ class SolverPlan:
         """Times any of this plan's solve programs has been (re)traced."""
         return self._traces
 
+    def _traced(self):
+        """Count a (re)trace of a solve program; its ``plan.trace`` span marks
+        on the timeline the call that traced (trace time only)."""
+        self._traces += 1
+        _metrics.counter("plan.traces").inc()
+        return _span("plan.trace", plan=self._id)
+
+    def _call_span(self, name: str, **attrs):
+        """The root span of one solve call: plan number, call counter, method."""
+        return _span(name, plan=self._id, call=next(self._call_ids), method=self.method,
+                     **attrs)
+
     def _tols(self, atol, rtol):
         return (
             jnp.float32(self.atol if atol is None else atol),
@@ -386,13 +399,17 @@ class SolverPlan:
         )
 
     def _execute(self, b, x0, atol, rtol) -> SolveResult:
-        if self.distributed:
+        """Prepare the call's arguments, then dispatch the pinned program."""
+        with _span("plan.solve.args"):  # tolerance scalars, x0 zeros
+            atol, rtol = self._tols(atol, rtol)
+            if x0 is None and not self.distributed:
+                x0 = jnp.zeros_like(b)
+        with _span("plan.solve.dispatch"):
+            if not self.distributed:
+                return self._run(b, x0, atol, rtol)
             if x0 is None:
                 return self._run(b, atol, rtol)
             return self._run_x0(b, x0, atol, rtol)
-        if x0 is None:
-            x0 = jnp.zeros_like(b)
-        return self._run(b, x0, atol, rtol)
 
     def solve(self, b, x0=None, atol: float | None = None, rtol: float | None = None) -> SolveResult:
         """Solve ``A x = b`` with this plan's pinned program.
@@ -404,14 +421,15 @@ class SolverPlan:
         With observability enabled (``repro.obs.enable()``) the solve is
         synchronized and timed, solve metrics are recorded, and a full
         :class:`~repro.obs.SolveReport` lands on ``self.last_report``.
-        The disabled path is untouched: async dispatch, zero extra work,
-        and a solve-loop jaxpr byte-identical to the uninstrumented one.
+        Otherwise dispatch stays async, and the call's spans (``plan.solve``
+        > ``plan.solve.args``, ``plan.solve.dispatch``) record only while a
+        profiler is capturing; the solve-loop jaxpr is the same either way.
         """
-        atol, rtol = self._tols(atol, rtol)
         if not _obs_enabled():
-            return self._execute(b, x0, atol, rtol)
+            with self._call_span("plan.solve"):
+                return self._execute(b, x0, atol, rtol)
         traces_before = self._traces
-        with _span("plan.solve", method=self.method, n=self.n) as sp:
+        with self._call_span("plan.solve", n=self.n) as sp:
             t0 = time.perf_counter()
             res = self._execute(b, x0, atol, rtol)
             jax.block_until_ready(res)
@@ -462,9 +480,10 @@ class SolverPlan:
         batch metrics are recorded.
         """
         if not _obs_enabled():
-            return self._execute_batched(B, x0, atol, rtol)
+            with self._call_span("plan.solve_batched"):
+                return self._execute_batched(B, x0, atol, rtol)
         traces_before = self._traces
-        with _span("plan.solve_batched", k=int(B.shape[0]), n=self.n) as sp:
+        with self._call_span("plan.solve_batched", k=int(B.shape[0]), n=self.n) as sp:
             t0 = time.perf_counter()
             res = self._execute_batched(B, x0, atol, rtol)
             jax.block_until_ready(res)
@@ -519,9 +538,8 @@ class SolverPlan:
         if with_x0:
             def _inner(B, X0, atol, rtol):
                 # warm starts via the shifted systems A d_k = b_k - A x0_k
-                self._traces += 1
-                _metrics.counter("plan.traces").inc()
-                res = _solve_rhs_batch(B - jax.vmap(lambda v: spmv(A, v))(X0), atol, rtol)
+                with self._traced():
+                    res = _solve_rhs_batch(B - jax.vmap(lambda v: spmv(A, v))(X0), atol, rtol)
                 return SolveResult(
                     x=X0 + res.x, iterations=res.iterations,
                     residual_norm=res.residual_norm, converged=res.converged,
@@ -529,9 +547,8 @@ class SolverPlan:
                 )
         else:
             def _inner(B, atol, rtol):
-                self._traces += 1
-                _metrics.counter("plan.traces").inc()
-                return _solve_rhs_batch(B, atol, rtol)
+                with self._traced():
+                    return _solve_rhs_batch(B, atol, rtol)
 
         jitted = jax.jit(_inner)
         self._batched_runners[(k, with_x0)] = jitted
@@ -543,15 +560,17 @@ class SolverPlan:
         return self._run_batched
 
     def _execute_batched(self, B, x0, atol, rtol) -> SolveResult:
-        atol, rtol = self._tols(atol, rtol)
-        if self.distributed:
+        with _span("plan.solve_batched.args"):
+            atol, rtol = self._tols(atol, rtol)
+            if x0 is None and not self.distributed:
+                x0 = jnp.zeros_like(B)
+        with _span("plan.solve_batched.dispatch"):
+            if not self.distributed:
+                return self._batched_single()(B, x0, atol, rtol)
             run = self._batched_distributed(int(B.shape[0]), x0 is not None)
             if x0 is None:
                 return run(B, atol, rtol)
             return run(B, x0, atol, rtol)
-        if x0 is None:
-            x0 = jnp.zeros_like(B)
-        return self._batched_single()(B, x0, atol, rtol)
 
     def lower(self, b) -> "jax.stages.Lowered":
         """The program ``solve(b)`` (1-D ``b``) or ``solve_batched(b)``

@@ -286,9 +286,13 @@ class TestRegistryHygiene:
             register_spmv(_PlanTestMat, "custom", fn)
         register_spmv(_PlanTestMat, "custom", fn, overwrite=True)
 
-    def test_register_reducer_overwrite_guard(self):
+    def test_register_reducer_overwrite_guard(self, monkeypatch):
+        from repro.core import reduce
         from repro.core.reduce import register_reducer
 
+        # a registry copy for this test: tests in other files enumerate the
+        # reducers, and this factory's reducer has no ``.array``
+        monkeypatch.setattr(reduce, "_REDUCERS", dict(reduce._REDUCERS))
         factory = lambda axis: (lambda g, d, nn: (g, d, nn))  # noqa: E731
         register_reducer("_plan_test_red", factory)
         with pytest.raises(ValueError, match="already registered"):

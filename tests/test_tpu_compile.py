@@ -4,7 +4,8 @@ Nothing runs: each kernel is lowered and compiled for a chip that is
 described (``v5e:2x2``), not attached, at the widths ``chip_smoke.py``
 solves — the 27-point stencil on a 104^3 grid (row tile 11264) and the
 125-point stencil on a 44^3 grid (row tile 4096). Each compiled program
-must contain the kernel (``tpu_custom_call``). The persistent compilation
+must contain the kernel (``tpu_custom_call``), named by its package so a
+profile of the chip finds it under that name. The persistent compilation
 cache is off around these compiles: an entry written for a described chip
 cannot be read back without one.
 """
@@ -60,9 +61,10 @@ def _case(name):
     return offsets, n, bw, tile
 
 
-def _assert_kernel(fn, *shapes):
+def _assert_kernel(name, fn, *shapes):
     hlo = jax.jit(fn).lower(*shapes).compile().as_text()
-    assert "tpu_custom_call" in hlo
+    kernels = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert kernels and all(f"%{name}" in line for line in kernels), kernels
 
 
 def _shape(sharding, shape):
@@ -74,7 +76,7 @@ def test_spmv_dia(one_chip, case):
     offsets, n, bw, tile = _case(case)
     assert _padded_tile("pallas", bw, None) == tile
     n_pad = _padded_length(n, tile)
-    _assert_kernel(lambda d, x: spmv_dia_padded(d, offsets, x, tile=tile, interpret=False),
+    _assert_kernel("spmv_dia", lambda d, x: spmv_dia_padded(d, offsets, x, tile=tile, interpret=False),
                    _shape(one_chip, (len(offsets), n_pad)), _shape(one_chip, (n_pad,)))
 
 
@@ -90,7 +92,7 @@ def test_fused_iter(one_chip, case):
         return fused_iter_padded(data, offsets, v[:9], v[9], v[10], v[11],
                                  tile=tile, interpret=False)
 
-    _assert_kernel(step, _shape(one_chip, (len(offsets), n_pad)), *[vec] * 10, scalar, scalar)
+    _assert_kernel("fused_iter", step, _shape(one_chip, (len(offsets), n_pad)), *[vec] * 10, scalar, scalar)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
@@ -103,12 +105,12 @@ def test_fused_vma(one_chip, case):
     def core(*v):
         return fused_vma_dots_padded(v[:10], v[10], v[11], v[12], interpret=False)
 
-    _assert_kernel(core, *[view] * 11, scalar, scalar)
+    _assert_kernel("fused_vma", core, *[view] * 11, scalar, scalar)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_fused_dot(one_chip, case):
     _, n, _, _ = _case(case)
     view = _shape(one_chip, (ceil_to(n, DOT_ROWS * LANE) // LANE, LANE))
-    _assert_kernel(lambda r, u, w: sum_dot_partials(fused_dots_padded(r, u, w, interpret=False)),
+    _assert_kernel("fused_dot", lambda r, u, w: sum_dot_partials(fused_dots_padded(r, u, w, interpret=False)),
                    view, view, view)
