@@ -291,7 +291,23 @@ def run_pipecg(
         norm = state[-2]
         return (i < maxiter) & (norm > thresh)
 
-    def body(state):
+    def _replace(x, p):
+        # Residual replacement (Cools & Vanroose): re-derive every
+        # auxiliary vector from its definition to arrest the recurrence
+        # roundoff drift that plain PIPECG accumulates.
+        r = b - replace_spmv_fn(x)
+        u = pc_fn(r)
+        w = replace_spmv_fn(u)
+        s = replace_spmv_fn(p)
+        q = pc_fn(s)
+        z = replace_spmv_fn(q)
+        m = pc_fn(w)
+        n = jnp.zeros((0,), dtype) if fused_spmv else replace_spmv_fn(m)
+        gamma, delta, nn = reducer(dot_f32(r, u), dot_f32(w, u), dot_f32(u, u))
+        return r, u, w, s, q, z, m, n, gamma, delta, jnp.sqrt(nn)
+
+    def step(state, replace: bool):
+        """One iteration; ``replace`` (static) ends it with a replacement."""
         (i, x, r, u, w, z, q, s, p, m, n,
          gamma, gamma_prev, delta, alpha_prev, norm, hist) = state
         # scalars (lines 5-9) — consume *previous* iteration's reductions
@@ -314,40 +330,14 @@ def run_pipecg(
         # the reduction(s): results consumed next iteration only
         with trace_scope("pipecg.iteration.reduce"):
             gamma_new, delta_new, uu = reducer(g_p, d_p, n_p)
-        if not fused_spmv:
+        if not (fused_spmv or replace):  # a replacement re-derives n itself
             # SPMV (line 22) — independent of the reductions: overlap target
             with trace_scope("pipecg.iteration.spmv"):
                 n = spmv_fn(m)
         norm_new = jnp.sqrt(uu)
-
-        if replace_every > 0:
-            # Residual replacement (Cools & Vanroose): periodically re-derive
-            # every auxiliary vector from its definition to arrest the
-            # recurrence roundoff drift that plain PIPECG accumulates.
-            def _replace(args):
-                with trace_scope("pipecg.residual_replacement"):
-                    return _replace_body(args)
-
-            def _replace_body(args):
-                x, p, *_ = args
-                r = b - replace_spmv_fn(x)
-                u = pc_fn(r)
-                w = replace_spmv_fn(u)
-                s = replace_spmv_fn(p)
-                q = pc_fn(s)
-                z = replace_spmv_fn(q)
-                m = pc_fn(w)
-                n = jnp.zeros((0,), dtype) if fused_spmv else replace_spmv_fn(m)
-                gamma, delta, nn = reducer(dot_f32(r, u), dot_f32(w, u), dot_f32(u, u))
-                return x, p, r, u, w, s, q, z, m, n, gamma, delta, jnp.sqrt(nn)
-
-            do_rr = (i > 0) & (jnp.mod(i + 1, replace_every) == 0)
-            (x, p, r, u, w, s, q, z, m, n, gamma_new, delta_new, norm_new) = jax.lax.cond(
-                do_rr,
-                _replace,
-                lambda args: args,
-                (x, p, r, u, w, s, q, z, m, n, gamma_new, delta_new, norm_new),
-            )
+        if replace:
+            with trace_scope("pipecg.residual_replacement"):
+                r, u, w, s, q, z, m, n, gamma_new, delta_new, norm_new = _replace(x, p)
 
         hist = hist.at[i + 1].set(norm_new.astype(jnp.float32))
         return (
@@ -355,12 +345,33 @@ def run_pipecg(
             gamma_new, gamma, delta_new, alpha, norm_new, hist,
         )
 
+    if replace_every > 0:
+        # Replacement after iterations i with i > 0 and (i + 1) % k == 0.
+        # Plain iterations run in inner loops that stop short of each
+        # replacement; the outer loop runs the replacing iteration, its
+        # stop test the guard. No cond passes vectors through, so each
+        # stays in one loop-carried buffer, and under vmap the replacement
+        # runs once per period. (A cond around the replacing step also made
+        # XLA sink a second copy of a constant operator into its branch.)
+        def plain(state):
+            i = state[0]
+            return cond(state) & ~((i > 0) & (jnp.mod(i + 1, replace_every) == 0))
+
+        def run_plain(state):
+            return jax.lax.while_loop(plain, lambda st: step(st, False), state)
+
+        def loop(state):
+            return jax.lax.while_loop(cond, lambda st: run_plain(step(st, True)), run_plain(state))
+    else:
+        def loop(state):
+            return jax.lax.while_loop(cond, lambda st: step(st, False), state)
+
     acc = gamma0.dtype
     state = (
         jnp.int32(0), x0, r0, u0, w0, zv, zv, zv, zv, m0, n0,
         gamma0, jnp.ones((), acc), delta0, jnp.ones((), acc), norm0, hist0,
     )
-    out = jax.lax.while_loop(cond, body, state)
+    out = loop(state)
     i, x, norm, hist = out[0], out[1], out[-2], out[-1]
     return i, x, norm, norm <= thresh, hist
 

@@ -34,14 +34,20 @@ def _rand(n, dtype, seed=0):
 
 
 class TestFusedVMA:
-    @pytest.mark.parametrize("n", SIZES)
+    # 90112 = 704 rows of 128: two grid steps of 352 rows
+    @pytest.mark.parametrize("n", SIZES + [90112])
     @pytest.mark.parametrize("dtype", DTYPES)
-    def test_matches_ref(self, n, dtype):
+    @pytest.mark.parametrize("donate", [False, True])
+    def test_matches_ref(self, n, dtype, donate):
         vecs = [_rand(n, dtype, seed=i) for i in range(10)]
         inv = jnp.abs(_rand(n, dtype, seed=99)) + 0.5
         alpha, beta = 0.37, 0.81
-        out_k = fused_vma_dots(*vecs, inv, alpha, beta)
         out_r = fused_vma_dots_ref(*vecs, inv, alpha, beta)
+        if donate:  # the kernel updates z q s p x r u w m in their buffers
+            run = jax.jit(fused_vma_dots, donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7, 9))
+            out_k = run(*[v + 0 for v in vecs], inv, alpha, beta)
+        else:
+            out_k = fused_vma_dots(*vecs, inv, alpha, beta)
         for i, (a, b) in enumerate(zip(out_k[:9], out_r[:9])):
             np.testing.assert_allclose(
                 np.asarray(a, np.float64), np.asarray(b, np.float64), **_tol(dtype)
@@ -124,6 +130,18 @@ class TestRowTile:
         t = _padded_tile("pallas", bandwidth, tile)
         n_pad = _padded_length(100_003, t)
         assert n_pad >= 100_003 and n_pad % t == 0 and n_pad % 4096 == 0
+
+
+class TestFusedVMABlockRows:
+    """``fused_vma`` grid steps: the largest multiple of 32 rows that
+    divides the row count, at most 512 rows."""
+
+    @pytest.mark.parametrize("rows,block", [(32, 32), (160, 160), (704, 352), (8800, 352),
+                                            (544, 32), (1024, 512), (4096, 512)])
+    def test_rule(self, rows, block):
+        from repro.kernels.fused_vma.kernel import block_rows
+
+        assert block_rows(rows) == block
 
 
 class TestVmap:

@@ -140,6 +140,122 @@ class TestEquivalence:
         np.testing.assert_allclose(np.asarray(r1.x), np.asarray(r2.x), rtol=1e-4, atol=1e-5)
 
 
+def _pipecg_reference(A, b, inv_diag, rtol, maxiter, k):
+    """Jacobi PIPECG with residual replacement after iterations k, 2k, ...,
+    one eager step at a time (the schedule written out plainly)."""
+    from repro.core.iteration import dot_f32, pipecg_vma_core
+
+    Av = lambda v: spmv(A, v)
+
+    def derive(x, p):
+        r = b - Av(x)
+        u = inv_diag * r
+        w = Av(u)
+        s = Av(p)
+        q = inv_diag * s
+        z = Av(q)
+        m = inv_diag * w
+        return r, u, w, s, q, z, m, Av(m), dot_f32(r, u), dot_f32(w, u), jnp.sqrt(dot_f32(u, u))
+
+    x = jnp.zeros_like(b)
+    r, u, w, _, _, _, m, n, gamma, delta, norm = derive(x, x)
+    z = q = s = p = jnp.zeros_like(b)
+    thresh = rtol * norm
+    hist = [float(norm)]
+    gamma_prev = alpha = None
+    i = 0
+    while i < maxiter and norm > thresh:
+        beta = gamma / gamma_prev if i > 0 else 0.0
+        alpha = gamma / (delta - beta * gamma / alpha) if i > 0 else gamma / delta
+        z, q, s, p, x, r, u, w, m, (g, d, uu) = pipecg_vma_core(
+            z, q, s, p, x, r, u, w, n, m, inv_diag, alpha, beta)
+        n, norm = Av(m), jnp.sqrt(uu)
+        if i > 0 and (i + 1) % k == 0:
+            r, u, w, s, q, z, m, n, g, d, norm = derive(x, p)
+        gamma_prev, gamma, delta = gamma, g, d
+        hist.append(float(norm))
+        i += 1
+    return i, x, np.asarray(hist)
+
+
+class TestResidualReplacementSchedule:
+    """``replace_every=k`` replaces after iterations k, 2k, ... (0-based
+    iteration i with i > 0 and (i + 1) % k == 0), on every core."""
+
+    def test_replacements_follow_iterations_10_and_20(self):
+        from repro.core.iteration import pipecg_vma_core, run_pipecg
+
+        A = poisson27(6)
+        _, b = _system(A)
+        inv = jacobi(A).inv_diag
+        events = []
+
+        def core(*args):
+            jax.debug.callback(lambda: events.append("C"), ordered=True)
+            return pipecg_vma_core(*args)
+
+        def replace_spmv(v):
+            jax.debug.callback(lambda: events.append("R"), ordered=True)
+            return spmv(A, v)
+
+        res = jax.jit(lambda b: run_pipecg(
+            b, jnp.zeros_like(b), spmv_fn=lambda v: spmv(A, v), pc_fn=lambda r: inv * r,
+            core=core, inv_diag=inv, atol=0.0, rtol=0.0, maxiter=25, replace_every=10,
+            replace_spmv_fn=replace_spmv))(b)
+        jax.block_until_ready(res)
+        jax.effects_barrier()
+        assert int(res[0]) == 25
+        assert "".join(events) == "C" * 10 + "R" * 5 + "C" * 10 + "R" * 5 + "C" * 5
+
+    @pytest.mark.parametrize("engine", ["jnp", "pallas", "fused_iter"])
+    def test_cores_match_reference_loop(self, engine):
+        A = poisson27(8)
+        b = jnp.cos(jnp.arange(A.n) * 0.37).astype(jnp.float32)
+        M = jacobi(A)
+        it, x, hist = _pipecg_reference(A, b, M.inv_diag, 1e-5, 200, 10)
+        res = pipecg(A, b, M=M, atol=0.0, rtol=1e-5, maxiter=200, engine=engine, replace_every=10)
+        assert it > 10 and int(res.iterations) == it
+        np.testing.assert_allclose(np.asarray(res.x), np.asarray(x), rtol=1e-4, atol=1e-5)
+        # f32 roundoff of a different op order: about 4e-4 at most here
+        np.testing.assert_allclose(np.asarray(res.history)[: it + 1], hist, rtol=5e-3)
+
+    @pytest.mark.parametrize("replace_every", [0, 10])
+    def test_launches_counted_per_iteration(self, replace_every):
+        from repro.kernels.common import launches_per_iteration
+
+        A = poisson27(5)
+        b = jnp.ones((A.n,), jnp.float32)
+
+        def run(engine, **kw):
+            return lambda bb: pipecg(A, bb, M=jacobi(A), atol=0.0, rtol=0.0, maxiter=30,
+                                     engine=engine, replace_every=replace_every, **kw).x
+
+        assert launches_per_iteration(run("fused_iter"), b) == 1
+        assert launches_per_iteration(run("pallas", spmv_engine="pallas"), b) == 2
+        assert launches_per_iteration(run("jnp"), b) == 0
+
+    def test_batched_replacement_once_per_period(self):
+        import repro
+        from repro.kernels.common import count_primitive, while_body_jaxpr
+
+        A = poisson27(6)
+        p = repro.plan(A, method="pipecg", engine="pallas", spmv_engine="pallas", M="jacobi",
+                       atol=0.0, rtol=1e-6, maxiter=200, replace_every=10)
+        B = jnp.stack([jnp.cos(jnp.arange(A.n) * (k + 1.0)) for k in range(3)]).astype(jnp.float32)
+        res = p.solve_batched(B)
+        assert bool(jnp.all(res.converged))
+        for k in range(3):
+            single = p.solve(B[k])
+            assert int(res.iterations[k]) == int(single.iterations)
+            true_rel = jnp.linalg.norm(B[k] - spmv(A, res.x[k])) / jnp.linalg.norm(B[k])
+            assert float(true_rel) < 1e-5
+        # the batched iteration runs one fused_vma and one SPMV per lane;
+        # the replacement (5 SPMVs per lane) stays out of it
+        batched = jax.vmap(p._inner, in_axes=(0, 0, None, None))
+        jaxpr = jax.make_jaxpr(batched)(B, jnp.zeros_like(B), jnp.float32(0), jnp.float32(1e-6))
+        assert count_primitive(while_body_jaxpr(jaxpr.jaxpr), "pallas_call") == 2 * 3
+
+
 class TestEdgeCases:
     def test_zero_rhs(self):
         A = poisson27(5)

@@ -8,15 +8,24 @@ must contain the kernel (``tpu_custom_call``), named by its package so a
 profile of the chip finds it under that name. The persistent compilation
 cache is off around these compiles: an entry written for a described chip
 cannot be read back without one.
+
+The last tests compile the whole padded ``pallas`` solve: at HPCG's 104^3
+shapes they count the copies XLA puts into its loops (each recurrence
+vector must live in one buffer for the whole solve), and in a plan's
+program they count the copies of the operator it holds as a constant.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.pipecg import _padded_length, _padded_tile
+import repro
+
+from repro.core.pipecg import _padded_length, _padded_tile, _pipecg_impl
+from repro.core.preconditioners import JacobiPC
 from repro.kernels.common import LANE, ceil_to, sum_dot_partials
 from repro.kernels.fused_dot.kernel import TILE_ROWS as DOT_ROWS
 from repro.kernels.fused_dot.kernel import fused_dots_padded
@@ -24,6 +33,8 @@ from repro.kernels.fused_iter import fused_iter_tile
 from repro.kernels.fused_iter.kernel import fused_iter_padded
 from repro.kernels.fused_vma.kernel import fused_vma_dots_padded
 from repro.kernels.spmv_dia.kernel import spmv_dia_padded
+from repro.sparse import poisson27
+from repro.sparse.formats import DIAMatrix
 from repro.sparse.stencil import stencil_offsets
 
 # (stencil radius, grid side, expected row tile)
@@ -114,3 +125,66 @@ def test_fused_dot(one_chip, case):
     view = _shape(one_chip, (ceil_to(n, DOT_ROWS * LANE) // LANE, LANE))
     _assert_kernel("fused_dot", lambda r, u, w: sum_dot_partials(fused_dots_padded(r, u, w, interpret=False)),
                    view, view, view)
+
+
+def _loop_computations(hlo):
+    """{name: instruction lines} of every while body and cond branch."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    names = []
+    for line in hlo.splitlines():
+        if " while(" in line:
+            names += re.findall(r"body=%([\w.\-]+)", line)
+        for branches in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            names += [b.strip().lstrip("%") for b in branches.split(",")]
+        names += re.findall(r"(?:true|false)_computation=%([\w.\-]+)", line)
+    return {name: comps[name] for name in names}
+
+
+@pytest.mark.parametrize("replace_every", [10, 0])
+def test_pallas_solve_loops_copy_no_vectors(one_chip, monkeypatch, replace_every):
+    """HPCG's 27-point operator at 104^3, Jacobi, the ``pallas`` core, the
+    operator an argument: no while body or cond branch copies a vector,
+    and ``fused_vma`` updates its vectors in place, in HBM."""
+    offsets, n, bw, tile = _case("27pt-104")
+    n_pad = _padded_length(n, tile)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled kernels
+
+    def solve(A, b, M, x0, atol, rtol):
+        return _pipecg_impl(A, b, M, x0, atol, rtol, 500, "pallas", "auto", replace_every, None, None)
+
+    vec, scalar = _shape(one_chip, (n,)), _shape(one_chip, ())
+    A = DIAMatrix(_shape(one_chip, (len(offsets), n)), offsets, n)
+    hlo = jax.jit(solve).lower(A, vec, JacobiPC(vec), vec, scalar, scalar).compile().as_text()
+
+    loops = _loop_computations(hlo)
+    assert any("fused_vma" in line for lines in loops.values() for line in lines)
+    vector = re.compile(rf"f32\[({n_pad // LANE},{LANE}|{n_pad})\]")
+    copies = [line for lines in loops.values() for line in lines
+              if re.search(r" (copy|copy-start)\(", line) and vector.search(line.split("=", 1)[1])]
+    assert copies == []
+    calls = [line for line in hlo.splitlines() if "custom-call(" in line and "%fused_vma" in line]
+    assert calls and all("output_to_operand_aliasing" in line for line in calls), calls
+    # its nine vector results (the loop's state) in HBM, not VMEM (S(1))
+    results = [re.findall(rf"f32\[{n_pad // LANE},{LANE}\]\{{[^}}]*\}}", line.split("custom-call(")[0])
+               for line in calls]
+    assert all(len(r) == 9 and not any("S(1)" in v for v in r) for r in results), results
+
+
+def test_plan_program_holds_its_operator_once(one_chip, monkeypatch):
+    """A plan closes over its operator, so its compiled solve holds the
+    padded diagonals as a constant: once, not again in a loop or branch."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled kernels
+    A = poisson27(48)
+    plan = repro.plan(A, method="pipecg", engine="pallas", M="jacobi", atol=0.0, rtol=3e-6,
+                      maxiter=2000, replace_every=10)
+    vec, scalar = _shape(one_chip, (A.n,)), _shape(one_chip, ())
+    hlo = jax.jit(plan._inner).lower(vec, vec, scalar, scalar).compile().as_text()
+    assert len(re.findall(rf"= f32\[{A.n_diags},\d+\]\S* constant\(", hlo)) == 1
